@@ -1,13 +1,9 @@
-"""jax version-compatibility shims (single home for API drift).
+"""SPMD entry points the codebase uses (single home for jax API drift).
 
-The codebase targets the modern public SPMD APIs — ``jax.shard_map`` with
-``check_vma`` and the ambient-mesh ``jax.set_mesh`` — but deployments pin a
-range of jax versions; on 0.4.x those live at
-``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``) and the
-ambient mesh is the ``Mesh`` context manager + ``thread_resources``.
-
-Use ``compat.shard_map`` / ``compat.set_mesh`` everywhere instead of
-touching ``jax.*`` directly, so the version split stays in this file.
+``jax.shard_map`` (with ``check_vma``) and the ambient-mesh
+``jax.set_mesh``, as in the installed jax (0.9).  Call ``compat.shard_map``
+/ ``compat.set_mesh`` instead of touching ``jax.*`` directly, so a future
+API change lands in this file only.
 """
 
 from __future__ import annotations
@@ -17,39 +13,11 @@ import jax
 __all__ = ["shard_map", "set_mesh"]
 
 
-if hasattr(jax, "shard_map"):                               # jax >= 0.6
-
-    def shard_map(f, *, mesh=None, in_specs, out_specs, check=False):
-        kw = {} if mesh is None else {"mesh": mesh}
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check, **kw)
-
-else:                                                       # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    from jax.interpreters import pxla
-
-    def _ambient_mesh():
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError(
-                "shard_map without an explicit mesh needs an ambient mesh "
-                "(enter one with repro.compat.set_mesh)")
-        return mesh
-
-    def shard_map(f, *, mesh=None, in_specs, out_specs, check=False):
-        def wrapped(*args):
-            m = mesh if mesh is not None else _ambient_mesh()
-            return _shard_map(f, m, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check)(*args)
-        return wrapped
+def shard_map(f, *, mesh=None, in_specs, out_specs, check=False):
+    kw = {} if mesh is None else {"mesh": mesh}
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check, **kw)
 
 
-if hasattr(jax, "set_mesh"):                                # jax >= 0.6
-
-    def set_mesh(mesh):
-        return jax.set_mesh(mesh)
-
-else:                                                       # jax 0.4.x:
-    # Mesh is itself the ambient-mesh context manager
-    def set_mesh(mesh):
-        return mesh
+def set_mesh(mesh):
+    return jax.set_mesh(mesh)

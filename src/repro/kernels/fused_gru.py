@@ -57,6 +57,7 @@ def fused_gru(x, h, wx, wh, bx, bh, *, block_b: int = 128,
     grid = (pl.cdiv(b, block_b),)
     return pl.pallas_call(
         _gru_kernel,
+        name="fused_gru",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, d_in), lambda i: (i, 0)),
@@ -148,6 +149,7 @@ def fused_gru_bwd(g, x, h, wx, wh, bx, bh, *, block_b: int = 128,
     full = lambda rows, cols: pl.BlockSpec((rows, cols), lambda i: (0, 0))
     return pl.pallas_call(
         kernel,
+        name="fused_gru_bwd",
         grid=grid,
         in_specs=[
             row_spec(d_h),                               # g

@@ -5,10 +5,10 @@ Each op picks its execution path:
   * ``backend="interpret"``  — the same kernel body executed in Python on
                                CPU (correctness validation; what tests use),
   * ``backend="xla"``        — the pure-jnp oracle from ``ref.py`` (what the
-                               models use on CPU and in dry-runs; on TPU
-                               deployments flip the default to "pallas").
+                               models use without ``use_pallas``).
 
-``default_backend()`` resolves "auto": pallas on TPU, xla elsewhere.  The
+``default_backend()`` resolves "auto": pallas on TPU, xla elsewhere; a
+failure to find any device is raised, never read as "CPU".  The
 ``REPRO_KERNEL_BACKEND`` environment variable overrides the "auto"
 resolution (e.g. ``REPRO_KERNEL_BACKEND=interpret`` exercises the Pallas
 kernel bodies on CPU without touching any config).
@@ -58,11 +58,7 @@ __all__ = ["default_backend", "default_bwd", "gru", "temporal_attention",
 
 @functools.cache
 def default_backend() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "xla"
+    return "pallas" if jax.devices()[0].platform == "tpu" else "xla"
 
 
 def _resolve(backend: str | None) -> str:

@@ -285,6 +285,7 @@ def dryrun_speed_tig(*, multi_pod: bool, save: bool = True,
     from repro.optim import adamw as _adamw
     from repro.tig.distributed import make_pac_epoch
     from repro.tig.models import init_params as tig_init
+    from repro.kernels.neighbor_sample import export_length
 
     n_parts = 512 if multi_pod else 256
     mesh = make_tig_mesh(n_parts)
@@ -299,7 +300,7 @@ def dryrun_speed_tig(*, multi_pod: bool, save: bool = True,
     n_edges = 4_300_999
     e_cap = n_edges // n_parts + n_parts  # balanced partitions (SEP)
     # per-device T-CSR export: 2 endpoint events per edge + K*depth pad
-    ev_cap = 2 * e_cap + k * cfg.n_layers
+    ev_cap = export_length(2 * e_cap, k, cfg.n_layers)
 
     def batch_tree():
         # device plan + sharded layout: per-chip (steps, ...) RAW edge
@@ -397,6 +398,9 @@ def main(argv=None):
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--no-save", action="store_true")
     args = ap.parse_args(argv)
+    from repro.launch.cache import setup_compile_cache
+
+    setup_compile_cache()
 
     meshes = [False, True] if args.both_meshes else [args.multi_pod]
     combos = []

@@ -285,17 +285,17 @@ def _start_heartbeat(run_dir: str, rank: int, interval: float) -> None:
 def _start_watchdog(run_dir: str, rank: int, peers: list[int],
                     interval: float, timeout: float) -> None:
     """Kill THIS worker (``EXIT_PEER_LOST``) when a peer stops
-    heartbeating without a ``done`` marker: a SIGKILLed peer leaves the
-    survivors hung inside a gloo collective that may never error out, so
-    liveness has to come from outside the collective stack."""
+    heartbeating without a ``done`` marker (a peer marked lost has had its
+    heartbeat removed): a SIGKILLed peer leaves the survivors hung inside
+    a gloo collective that may never error out, so liveness has to come
+    from outside the collective stack."""
     started = time.time()
 
     def watch():
         while True:
             time.sleep(interval)
             for p in peers:
-                if p == rank or os.path.exists(_done(run_dir, p)) \
-                        or os.path.exists(_lost(run_dir, p)):
+                if p == rank or os.path.exists(_done(run_dir, p)):
                     continue
                 age = _age(_hb(run_dir, p))
                 if age > timeout and time.time() - started > timeout:
@@ -317,6 +317,11 @@ def _init_with_retry(args) -> bool:
     # CPU collectives span processes through gloo; TPU pods skip this
     # (the default backend already crosses hosts)
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    if args.run_dir:
+        # elastic worker: a lost peer must reach the re-rank path (watchdog
+        # or HostLossError -> EXIT_PEER_LOST), not have the coordination
+        # service terminate this process
+        jax.config.update("jax_enable_recoverability", True)
     last = None
     for i in range(max(1, args.cluster_retries)):
         try:
@@ -357,6 +362,9 @@ def _run(args) -> int:
 
     import jax
 
+    from repro.launch.cache import setup_compile_cache
+
+    setup_compile_cache()
     if args.num_processes > 1 and not _init_with_retry(args):
         return EXIT_UNAVAILABLE
     if args.run_dir and len(peers) > 1:

@@ -139,6 +139,9 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args(argv)
+    from repro.launch.cache import setup_compile_cache
+
+    setup_compile_cache()
     if args.arch == "speed-tig":
         args.lr = args.lr or 1e-3
         args.batch = args.batch or 100

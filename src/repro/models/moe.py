@@ -66,7 +66,7 @@ def moe_apply(p: dict, x: jnp.ndarray, *, top_k: int, act: str,
     cap = t if dropless else int(max(1, -(-t * top_k // e)
                                      * capacity_factor))
     flat_e = top_i.reshape(-1)                                # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(t), top_k)
+    flat_t = jnp.arange(t * top_k) // top_k                   # (T*k,) token
     flat_w = top_p.reshape(-1).astype(x.dtype)
     order = jnp.argsort(flat_e, stable=True)
     se, st, sw = flat_e[order], flat_t[order], flat_w[order]

@@ -82,6 +82,7 @@ from repro.core.pac import (
     subgraph_mask,
 )
 from repro.core.sep import PartitionResult
+from repro.kernels.neighbor_sample import export_length
 from repro.optim import Optimizer
 from repro.tig.batching import (
     LocalStream,
@@ -484,13 +485,14 @@ def plan_epoch(
             # can map the leading axis (pad rows are never addressed —
             # indptr bounds stay within the real segment)
             # GLOBAL event cap, derivable from edge counts alone (export
-            # length = 2 endpoint events per edge + K*depth front pad), so
-            # a host planning only its own ranks pads identically
-            ev_cap = int((2 * edges_per_device
-                          + cfg.num_neighbors * cfg.n_layers).max())
+            # length = 2 endpoint events per edge + K*depth front pad,
+            # chunk-aligned), so a host planning only its own ranks pads
+            # identically
+            ev_len = [export_length(2 * int(n), cfg.num_neighbors,
+                                    cfg.n_layers) for n in edges_per_device]
+            ev_cap = max(ev_len)
             for k, e in zip(ranks, exports):
-                assert len(e["nbr"]) == 2 * edges_per_device[k] + \
-                    cfg.num_neighbors * cfg.n_layers, (k, len(e["nbr"]))
+                assert len(e["nbr"]) == ev_len[k], (k, len(e["nbr"]))
             pad = lambda v: np.pad(v, (0, ev_cap - len(v)))  # noqa: E731
             tcsr = {
                 "indptr": np.stack([e["indptr"] for e in exports]),

@@ -30,6 +30,8 @@ from typing import Callable, Iterable, Sequence, Union
 
 import numpy as np
 
+from repro.kernels.neighbor_sample import export_length
+
 __all__ = ["RecentNeighborBuffer", "NeighborSnapshot", "ChronoNeighborIndex"]
 
 Chunk = tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]
@@ -349,6 +351,10 @@ class ChronoNeighborIndex:
         (depth 1 = the single-window export of PR 6, byte-identical
         modulo the pad length).
 
+        The arrays are also BACK-PADDED to
+        ``kernels.neighbor_sample.export_length`` (a whole number of the
+        sampling kernel's DMA chunks); nothing addresses those slots.
+
         ``bat`` stores each event's search key ``batch + 1`` (history = 0)
         — per node it is non-decreasing in segment order, so bisecting for
         ``batch_of + 1`` reproduces ``sample``'s ``searchsorted`` over
@@ -364,8 +370,8 @@ class ChronoNeighborIndex:
         total = len(self._nbr)
 
         def padded(arr, dtype):
-            out = np.zeros(pad + total, dtype)
-            out[pad:] = arr
+            out = np.zeros(export_length(total, self.k, depth), dtype)
+            out[pad:pad + total] = arr
             return out
 
         return {

@@ -19,8 +19,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ops
-from repro.kernels.neighbor_sample import neighbor_sample_fwd
+from repro.kernels import ops, ref
+from repro.kernels.neighbor_sample import (
+    CHUNK,
+    export_length,
+    neighbor_sample_fwd,
+)
 from repro.kernels.ref import sample_ref
 from repro.roofline.kernel_bytes import epoch_plan_bytes, sample_bytes
 from repro.tig.cache import lru_get
@@ -85,6 +89,37 @@ def test_sampler_edge_cases_match_host():
     _assert_matches_host(index, nodes, per_row)
 
 
+@pytest.mark.parametrize("k,depth", [(10, 1), (16, 2)],
+                         ids=["tig", "tig_mxu"])
+def test_sampler_paper_widths_kernel_matches_oracle(k, depth):
+    """The chunked kernel at the model's K and layer count (L * 3B query
+    rows, per-row batch index and window) equals the oracle, on a stream
+    whose event arrays span several 1024-event chunks and hub segments
+    longer than one chunk."""
+    from repro.tig.data import synthetic_tig
+
+    g = synthetic_tig("small", seed=3)
+    b = 200
+    index = ChronoNeighborIndex(g.src, g.dst, g.t, np.arange(g.num_edges),
+                                g.num_nodes, k, b)
+    tcsr = {kk: jnp.asarray(v)
+            for kk, v in index.device_export(depth=depth).items()}
+    assert tcsr["nbr"].shape[0] % CHUNK == 0
+    rng = np.random.default_rng(0)
+    rows = 3 * b * depth
+    nodes = jnp.asarray(rng.integers(0, g.num_nodes, rows), jnp.int32)
+    batch_of = jnp.asarray(rng.integers(0, index.num_batches, rows),
+                           jnp.int32)
+    win = jnp.asarray(np.repeat(np.arange(depth - 1, -1, -1), 3 * b),
+                      jnp.int32)
+    args = (tcsr["indptr"], tcsr["nbr"], tcsr["t"], tcsr["eidx"],
+            tcsr["bat"], nodes, batch_of)
+    want = ref.sample_ref(*args, k, win)
+    got = neighbor_sample_fwd(*args, k=k, interpret=True, window=win)
+    for a, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
 def test_sampler_degree_zero_and_all_newer_rows_are_fill():
     index = _crafted_index()
     for backend in ("xla", "interpret"):
@@ -92,6 +127,19 @@ def test_sampler_degree_zero_and_all_newer_rows_are_fill():
         np.testing.assert_array_equal(ids, -1)      # degree 0 / all newer
         np.testing.assert_array_equal(eix, -1)
         np.testing.assert_array_equal(tms, -1.0)
+
+
+def test_sampler_kernel_refuses_unaligned_events():
+    """The kernel DMAs whole chunks: event arrays that are not
+    ``export_length`` long are refused, not padded in the step."""
+    tcsr = _crafted_index().device_export()
+    assert len(tcsr["nbr"]) == export_length(16, 4)
+    cut = {k: jnp.asarray(v[:-1] if k != "indptr" else v)
+           for k, v in tcsr.items()}
+    with pytest.raises(ValueError, match="export_length"):
+        neighbor_sample_fwd(cut["indptr"], cut["nbr"], cut["t"], cut["eidx"],
+                            cut["bat"], jnp.arange(8, dtype=jnp.int32),
+                            jnp.int32(1), k=4, interpret=True)
 
 
 def test_sampler_k_larger_than_any_degree():

@@ -8,7 +8,8 @@ import pytest
 pytest.importorskip("hypothesis")  # optional dep: skip, don't error
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ref
+from repro.configs.speed_tig import TIG, TIG_MXU
+from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_flush import fused_flush_fwd
 from repro.kernels.fused_gru import fused_gru
@@ -92,6 +93,31 @@ def test_temporal_attn_empty_rows_zero():
     assert np.abs(got[0]).max() > 0.0
 
 
+@pytest.mark.parametrize("cfg", [TIG, TIG_MXU], ids=["tig", "tig_mxu"])
+def test_temporal_attention_paper_widths_match_ref(cfg):
+    """The padded, head-folded launch at the widths the chip runs (3B
+    query rows, K neighbors, n_heads x dim/n_heads) equals the oracle."""
+    b, k, h = 3 * cfg.batch_size, cfg.num_neighbors, cfg.n_heads
+    d = cfg.dim // h
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    q = rand(ks[0], (b, h, d))
+    kk = rand(ks[1], (b, k, h, d))
+    v = rand(ks[2], (b, k, h, d))
+    mask = jax.random.uniform(ks[3], (b, k)) > 0.3
+    mask = mask.at[:7].set(False)          # rows with no neighbor
+    got = ops.temporal_attention(q, kk, v, mask, backend="interpret")
+    want = ref.temporal_attention_ref(q, kk, v, mask)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-4)
+    g = rand(ks[4], (b, h, d))
+    loss = lambda f: lambda *a: jnp.sum(f(*a, mask) * g)  # noqa: E731
+    got_g = jax.grad(loss(lambda *a: ops.temporal_attention(
+        *a, backend="interpret")), argnums=(0, 1, 2))(q, kk, v)
+    want_g = jax.grad(loss(ref.temporal_attention_ref),
+                      argnums=(0, 1, 2))(q, kk, v)
+    for a, w in zip(got_g, want_g):
+        np.testing.assert_allclose(a, w, atol=1e-4, rtol=1e-4)
+
+
 # ------------------------------------------------------------- fused flush
 
 def flush_args(key, n, rows, dm, d, id_hi=None):
@@ -123,6 +149,24 @@ def test_fused_flush_property(seed, rows, n):
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("cfg", [TIG, TIG_MXU], ids=["tig", "tig_mxu"])
+def test_fused_flush_paper_widths_match_ref(cfg):
+    """2B pending rows at the model's (msg_dim, dim), on a table whose row
+    count is a multiple of neither the 8-row tile nor the 1024-element
+    ``last`` chunk."""
+    n, rows = 5_000, 2 * cfg.batch_size
+    args = list(flush_args(jax.random.PRNGKey(6), n, rows, cfg.msg_dim,
+                           cfg.dim, id_hi=n // 3))
+    args[0] = args[0].at[-5:].set(n)           # padding rows -> dump row
+    got = ops.fused_flush(*args, backend="interpret")
+    want = ref.flush_ref(*args)
+    # the gate pre-activations sum ~600 f32 terms of magnitude ~7 here, in
+    # a different order than the oracle: rounding reaches ~1e-5
+    for name, a, b in zip(("mem", "last", "mbar"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-5, err_msg=name)
 
 
 # --------------------------------------------------------- flash attention
