@@ -177,13 +177,15 @@ def scan_train_epoch(
 
     def step_body(params, opt_state, state, batch, b_of):
         if tcsr is not None:
-            batch = sample_batch_neighbors(batch, tcsr, b_of, cfg)
+            with jax.named_scope("tig.sample"):
+                batch = sample_batch_neighbors(batch, tcsr, b_of, cfg)
         (loss, (state, _aux)), grads = jax.value_and_grad(
             step_loss, has_aux=True
         )(params, state, batch, tables, cfg)
         if axis is not None:
             grads = jax.lax.pmean(grads, axis)
-        params, opt_state = opt.apply(grads, opt_state, params)
+        with jax.named_scope("tig.optimizer"):
+            params, opt_state = opt.apply(grads, opt_state, params)
         return params, opt_state, state, loss
 
     if not cycling:
@@ -244,11 +246,20 @@ def scan_train_epoch(
     return params, opt_state, backup, losses
 
 
+def _named_partial(fn, **bound):
+    """``functools.partial`` that keeps ``fn``'s name, so the compiled
+    program is ``jit_<fn>`` (a bare partial compiles as ``jit__unknown``)
+    in HLO dumps and profiler traces."""
+    out = functools.partial(fn, **bound)
+    out.__name__ = fn.__name__
+    return out
+
+
 def make_train_epoch(cfg: TIGConfig, opt: Optimizer):
-    """jit'd single-device epoch: (params, opt_state, state, batches,
-    tables) -> (params, opt_state, state, losses), donating the carried
-    buffers."""
-    fn = functools.partial(scan_train_epoch, cfg=cfg, opt=opt)
+    """jit'd single-device epoch ``jit_scan_train_epoch``: (params,
+    opt_state, state, batches, tables) -> (params, opt_state, state,
+    losses), donating the carried buffers."""
+    fn = _named_partial(scan_train_epoch, cfg=cfg, opt=opt)
     return jax.jit(fn, donate_argnums=_donate_args(0, 1, 2))
 
 
@@ -280,7 +291,8 @@ def scan_eval_stream(
     def scan_step(state, xs):
         batch, s = xs
         if tcsr is not None:
-            batch = sample_batch_neighbors(batch, tcsr, s, cfg)
+            with jax.named_scope("tig.sample"):
+                batch = sample_batch_neighbors(batch, tcsr, s, cfg)
         _loss, (state, aux) = step_loss(params, state, batch, tables, cfg)
         out = {"pos_logit": aux["pos_logit"],
                "neg_logit": aux["neg_logit"]}
@@ -302,8 +314,8 @@ _EVAL_PROGRAMS_MAX = 32          # bounded LRU: evict least-recently-USED,
 
 
 def make_eval_epoch(cfg: TIGConfig, *, collect_embeddings: bool = False):
-    """jit'd eval-stream program: (params, state, batches, tables) ->
-    (state, stacked aux).
+    """jit'd eval-stream program ``jit_scan_eval_stream``: (params, state,
+    batches, tables) -> (state, stacked aux).
 
     Programs are cached per (cfg, collect_embeddings) with LRU eviction
     (hits move to the back of the dict, the front is evicted): per-epoch
@@ -331,6 +343,6 @@ def make_eval_epoch(cfg: TIGConfig, *, collect_embeddings: bool = False):
     # mutation of the caller's cfg can't desync a cached program
     return lru_get(
         _EVAL_PROGRAMS, key, _EVAL_PROGRAMS_MAX,
-        lambda: jax.jit(functools.partial(
+        lambda: jax.jit(_named_partial(
             scan_eval_stream, cfg=dataclasses.replace(cfg),
             collect_embeddings=collect_embeddings)))

@@ -153,15 +153,17 @@ def init_params(key, cfg: TIGConfig) -> dict:
 
 
 def init_state(cfg: TIGConfig, num_local_nodes: int) -> dict:
+    """A fresh node memory (the host span ``tig.reset`` in a trace)."""
     n, b, d = num_local_nodes, cfg.batch_size, cfg.dim
-    return {
-        "mem": jnp.zeros((n + 1, d), jnp.float32),
-        "mem2": jnp.zeros((n + 1, d), jnp.float32),
-        "last": jnp.zeros((n + 1,), jnp.float32),
-        "pend_ids": jnp.full((2 * b,), n, jnp.int32),
-        "pend_raw": jnp.zeros((2 * b, cfg.raw_msg_dim), jnp.float32),
-        "pend_t": jnp.zeros((2 * b,), jnp.float32),
-    }
+    with jax.profiler.TraceAnnotation("tig.reset"):
+        return {
+            "mem": jnp.zeros((n + 1, d), jnp.float32),
+            "mem2": jnp.zeros((n + 1, d), jnp.float32),
+            "last": jnp.zeros((n + 1,), jnp.float32),
+            "pend_ids": jnp.full((2 * b,), n, jnp.int32),
+            "pend_raw": jnp.zeros((2 * b, cfg.raw_msg_dim), jnp.float32),
+            "pend_t": jnp.zeros((2 * b,), jnp.float32),
+        }
 
 
 # ---------------------------------------------------------------- memory ops
@@ -334,42 +336,46 @@ def step_loss(
                                       batch["eidx"], e_dump)]
 
     # 1) apply previous batch's messages (grads flow into MSG/UPD here)
-    state = flush_pending(params, cfg, state)
+    with jax.named_scope("tig.memory.flush"):
+        state = flush_pending(params, cfg, state)
 
     # 2) embeddings at time t from the just-updated memory — the three
     # roles share one (3B,)-fused embed call (one attention launch instead
     # of three; row-wise identical math)
     b = ids_s.shape[0]
     ids_all = jnp.concatenate([ids_s, ids_d, ids_n])
-    emb_all = embed_nodes(
-        params, cfg, state, tables, ids_all,
-        jnp.tile(batch["t"], 3),
-        jnp.concatenate([batch["nbr_src"], batch["nbr_dst"],
-                         batch["nbr_neg"]], axis=-2),
-        jnp.concatenate([batch["nbrt_src"], batch["nbrt_dst"],
-                         batch["nbrt_neg"]], axis=-2),
-        jnp.concatenate([batch["nbre_src"], batch["nbre_dst"],
-                         batch["nbre_neg"]], axis=-2),
-    )
+    with jax.named_scope("tig.embed"):
+        emb_all = embed_nodes(
+            params, cfg, state, tables, ids_all,
+            jnp.tile(batch["t"], 3),
+            jnp.concatenate([batch["nbr_src"], batch["nbr_dst"],
+                             batch["nbr_neg"]], axis=-2),
+            jnp.concatenate([batch["nbrt_src"], batch["nbrt_dst"],
+                             batch["nbrt_neg"]], axis=-2),
+            jnp.concatenate([batch["nbre_src"], batch["nbre_dst"],
+                             batch["nbre_neg"]], axis=-2),
+        )
     embeds = {"src": emb_all[:b], "dst": emb_all[b:2 * b],
               "neg": emb_all[2 * b:]}
 
     # 3) self-supervised link prediction loss (paper §II-C decoder g) —
     # pos and neg pairs stacked into ONE (2B, 2d) decoder launch
-    dec_in = jnp.concatenate([
-        jnp.concatenate([embeds["src"], embeds["dst"]], axis=-1),
-        jnp.concatenate([embeds["src"], embeds["neg"]], axis=-1)])
-    logits = mlp(params["dec"], dec_in)[:, 0]
-    pos_logit, neg_logit = logits[:b], logits[b:]
-    v = valid.astype(jnp.float32)
-    nv = jnp.clip(v.sum(), 1.0)
-    bce_pos = jax.nn.softplus(-pos_logit)
-    bce_neg = jax.nn.softplus(neg_logit)
-    loss = ((bce_pos + bce_neg) * v).sum() / (2.0 * nv)
+    with jax.named_scope("tig.decode"):
+        dec_in = jnp.concatenate([
+            jnp.concatenate([embeds["src"], embeds["dst"]], axis=-1),
+            jnp.concatenate([embeds["src"], embeds["neg"]], axis=-1)])
+        logits = mlp(params["dec"], dec_in)[:, 0]
+        pos_logit, neg_logit = logits[:b], logits[b:]
+        v = valid.astype(jnp.float32)
+        nv = jnp.clip(v.sum(), 1.0)
+        bce_pos = jax.nn.softplus(-pos_logit)
+        bce_neg = jax.nn.softplus(neg_logit)
+        loss = ((bce_pos + bce_neg) * v).sum() / (2.0 * nv)
 
     # 4) stash this batch's raw messages for the next step
-    new_state = _stash_messages(cfg, state, ids_s, ids_d, batch["t"],
-                                efeat, valid, params["time"])
+    with jax.named_scope("tig.memory.stash"):
+        new_state = _stash_messages(cfg, state, ids_s, ids_d, batch["t"],
+                                    efeat, valid, params["time"])
 
     aux = {
         "pos_logit": pos_logit,

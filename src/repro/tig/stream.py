@@ -575,13 +575,16 @@ class EpochPrefetcher:
         self._worker: Optional[threading.Thread] = None
 
     def _worker_loop(self) -> None:
+        import jax
+
         while True:
             job = self._inbox.get()
             if job is _STOP:
                 return
             epoch, out = job
             try:
-                plan = self._build(epoch)
+                with jax.profiler.TraceAnnotation("tig.plan"):
+                    plan = self._build(epoch)
                 if self._to_device is not None:
                     self._slot.acquire()
                     if self._closing.is_set():
@@ -591,7 +594,8 @@ class EpochPrefetcher:
                         self._slot.release()
                         continue
                     try:
-                        plan = self._to_device(plan)
+                        with jax.profiler.TraceAnnotation("tig.stage"):
+                            plan = self._to_device(plan)
                     except BaseException:
                         self._slot.release()
                         raise
@@ -647,15 +651,22 @@ class EpochPrefetcher:
     def get(self, epoch: int):
         """Block until the plan for ``epoch`` is ready (building it inline
         when the pipeline is disabled) and refill the pipeline to
-        ``depth`` epochs in flight."""
+        ``depth`` epochs in flight.  In a profiler trace the wait is the
+        span ``tig.plan_wait``; the worker's builds and stagings are
+        ``tig.plan`` and ``tig.stage``."""
+        import jax
+
         if not self._enabled:
-            plan = self._build(epoch)
+            with jax.profiler.TraceAnnotation("tig.plan"):
+                plan = self._build(epoch)
             if self._to_device is not None:
-                plan = self._to_device(plan)
+                with jax.profiler.TraceAnnotation("tig.stage"):
+                    plan = self._to_device(plan)
             return plan
         self._submit(epoch)
         out = self._futures.pop(epoch)
-        ok, plan = out.get()
+        with jax.profiler.TraceAnnotation("tig.plan_wait"):
+            ok, plan = out.get()
         if not ok:
             self._cancel()      # the pipeline is poisoned past this epoch
             raise plan

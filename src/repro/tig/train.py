@@ -134,16 +134,22 @@ def train_epoch(params, opt_state, state, batches, tables_j, epoch_fn,
     dicts); ``epoch_fn`` comes from ``engine.make_train_epoch``.  With
     ``tcsr`` (a staged ``ChronoNeighborIndex.device_export`` dict) the
     batches are a raw-edge ``plan="device"`` program and the scan samples
-    neighbor grids on device.  Returns mean loss over steps.
+    neighbor grids on device.
+
+    Returns ``(params, opt_state, state, mean_loss)``: the epoch's updated
+    carry and its mean loss over steps as a host float.  In a profiler
+    trace, ``tig.dispatch`` is the call that enqueues the epoch program and
+    ``tig.fetch`` the loss fetch, where the host blocks until the device
+    has run the epoch.
     """
     bj = device_batches(batches)
-    if tcsr is None:
+    kw = {} if tcsr is None else {"tcsr": tcsr}
+    with jax.profiler.TraceAnnotation("tig.dispatch"):
         params, opt_state, state, losses = epoch_fn(
-            params, opt_state, state, bj, tables_j)
-    else:
-        params, opt_state, state, losses = epoch_fn(
-            params, opt_state, state, bj, tables_j, tcsr=tcsr)
-    return params, opt_state, state, float(jnp.mean(losses))
+            params, opt_state, state, bj, tables_j, **kw)
+    with jax.profiler.TraceAnnotation("tig.fetch"):
+        mean_loss = float(jnp.mean(losses))
+    return params, opt_state, state, mean_loss
 
 
 @dataclasses.dataclass
