@@ -3,11 +3,10 @@
 ``flush_pending`` (repro.tig.models) applies the previous batch's stashed
 messages to node memory: segment-mean aggregation of the (R=2B, d_msg)
 pending messages per touched node, a GRU update of those nodes' memory
-rows, and a scatter of the new ``mem``/``last`` values.  The XLA path
-materializes two (N+1, d_msg) aggregation tables (scatter-add sums +
-counts), divides over the FULL table, gathers back, and functionally
-updates the (N+1, d) memory — O(N) HBM traffic per step for work that only
-touches 2B rows.  TGL (Zhou et al., 2022) identifies exactly this
+rows, and a scatter of the new ``mem``/``last`` values.  With (N+1, d_msg)
+aggregation tables (scatter-add sums + counts, as ``ref.flush_ref`` builds
+them) that is O(N) HBM traffic per step for work that only touches 2B
+rows.  TGL (Zhou et al., 2022) identifies exactly this
 mailbox/memory-update scatter as the step-time bottleneck at scale.
 
 This kernel does the whole pipeline in one ``pallas_call`` with O(R) HBM

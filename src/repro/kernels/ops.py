@@ -20,8 +20,9 @@ input residuals — one HBM pass per operand); ``bwd="oracle"`` (or
 ``REPRO_KERNEL_BWD=oracle``) falls back to differentiating the pure-jnp
 oracle from ``ref.py``, which is the parity reference and what the
 ``"xla"`` backend uses implicitly.  ``fused_flush`` always differentiates
-through its oracle (``ref.flush_ref``) — the backward is dominated by the
-same scatter/gather XLA handles for the forward XLA path.
+through its oracle (``ref.flush_ref``); ``models.flush_pending`` calls it
+under ``stop_gradient`` as the table write and takes the gradient through
+the 2B updated rows instead, so training never runs that backward.
 
 MXU alignment: the f32 TPU tile is (8, 128) and the MXU is 128x128, so
 kernels fed unaligned feature dims waste tile columns.  The Pallas-bound

@@ -55,7 +55,7 @@ def flush_ref(ids, msg, ts, mem, last, wx, wh, bx, bh):
     """Message-pipeline oracle: segment-mean aggregation of the pending
     messages + GRU memory update + scatter of ``mem``/``last``.
 
-    This is exactly the XLA path of ``repro.tig.models.flush_pending`` for
+    It computes the table write of ``repro.tig.models.flush_pending`` for
     the GRU flavors (the fused Pallas kernel in ``fused_flush.py`` is
     validated against it, and its custom VJP recomputes through it).
 

@@ -32,6 +32,11 @@ All functions are pure; state is a pytree:
 
 Batches are fixed-shape with a validity mask; invalid ids are remapped to the
 dump row, which is re-zeroed after every update.
+
+The tables are data with no gradient.  ``flush_pending`` hands the step its
+2B updated rows separately, and every memory read inside the step takes a
+row from them where the id is a live pending id, so a step's gradient
+never builds a value with one row per node.
 """
 
 from __future__ import annotations
@@ -168,52 +173,93 @@ def init_state(cfg: TIGConfig, num_local_nodes: int) -> dict:
 
 # ---------------------------------------------------------------- memory ops
 
-def _read_memory(cfg: TIGConfig, state_mem, state_mem2, ids):
-    if cfg.flavor == "tige":
-        return 0.5 * (state_mem[ids] + state_mem2[ids])
-    return state_mem[ids]
+def _first_match(q, ids, live):
+    """Whether each id of ``q`` (any shape) equals a live id of ``ids``
+    (R,), and the index of its first match (0 where none): an (..., R)
+    compare, O(|q| R), never a table with one row per node."""
+    hit = (q[..., None] == ids) & live
+    return hit.any(-1), jnp.argmax(hit, axis=-1)
 
 
-def flush_pending(params: dict, cfg: TIGConfig, state: dict) -> dict:
-    """Apply the stashed messages of the previous batch to memory (the
-    differentiable half of the TGN message-store trick), then clear them."""
+def _pending_mean(ids, live, msg):
+    """Mean pending message of each row's node, (R, dm): the scatter mean
+    of ``ref.flush_ref`` summed into R slots (each node's first live row)
+    instead of (N+1) rows, in the same order, so the values are the same.
+    Dead rows get 0, as the dump row's mean is."""
+    _, first = _first_match(ids, ids, live)
+    sums = jnp.zeros_like(msg).at[first].add(
+        jnp.where(live[:, None], msg, 0.0))
+    cnt = jnp.zeros(ids.shape, msg.dtype).at[first].add(
+        live.astype(msg.dtype))
+    mean = sums / jnp.clip(cnt, 1.0)[:, None]
+    return jnp.where(live[:, None], mean[first], 0.0)
+
+
+def _read_memory(cfg: TIGConfig, state: dict, fresh: dict, q):
+    """Memory rows of the node ids ``q`` (any shape): the flush's fresh rows
+    where ``q`` is a live pending id, the written table otherwise.  The
+    table is data: the gradient reaches the parameters only through the
+    (R, d) fresh rows."""
     n_dump = state["mem"].shape[0] - 1
+    hit, j = _first_match(q, fresh["ids"], fresh["ids"] < n_dump)
+
+    def read(rows, table):
+        return jnp.where(hit[..., None], rows[j],
+                         jax.lax.stop_gradient(table)[q])
+
+    if cfg.flavor == "tige":
+        return 0.5 * (read(fresh["rows"], state["mem"])
+                      + read(fresh["rows2"], state["mem2"]))
+    return read(fresh["rows"], state["mem"])
+
+
+def flush_pending(params: dict, cfg: TIGConfig,
+                  state: dict) -> tuple[dict, dict]:
+    """Apply the stashed messages of the previous batch to memory (the
+    differentiable half of the TGN message-store trick), then clear them.
+
+    Returns ``(state, fresh)``.  ``state`` holds the written tables, which
+    carry no gradient.  ``fresh = {"ids", "rows"[, "rows2"]}`` holds the R
+    updated rows ``upd(mean message, mem[ids])`` (TIGE: and its second
+    memory's), the only path from the parameters into memory; reads inside
+    the step take them through ``_read_memory``."""
+    sg = jax.lax.stop_gradient
+    mem_in, mem2_in = sg(state["mem"]), sg(state["mem2"])
+    n_dump = mem_in.shape[0] - 1
     ids = state["pend_ids"]
     raw = state["pend_raw"]
     ts = state["pend_t"]
     live = ids < n_dump
 
     msg = mlp(params["msg"], raw) if cfg.message_fn == "mlp" else raw
+    # mean-aggregate messages per node (paper: "simply mean message")
+    mbar = _pending_mean(ids, live, msg)
+    # the rows that carry the gradient take XLA's updater on every backend,
+    # so they follow the configured matmul precision: the Pallas GRU
+    # kernels' gate matmuls do not, and on a TPU v5e that rounding alone
+    # moved the first steps' gradient by 1.7e-4 of the median leaf
+    upd_fn = gru if cfg.updater == "gru" else rnn
+    rows = upd_fn(params["upd"], mbar, mem_in[ids])
 
     if cfg.updater == "gru" and cfg.use_pallas:
-        # fused message pipeline: segment-mean + GRU + mem/last scatter in
-        # one Pallas launch — O(2B) HBM traffic instead of the O(N)
-        # aggregation tables + functional scatter below
         from repro.kernels import ops
-        p = params["upd"]
-        mem, last, mbar = ops.fused_flush(
-            ids, msg, ts, state["mem"], state["last"],
-            p["xz"]["w"], p["hz"]["w"], p["xz"]["b"], p["hz"]["b"],
+        # the table write: segment-mean + GRU + mem/last scatter in one
+        # Pallas launch, in place, O(2B) HBM traffic
+        w = sg(params["upd"])
+        mem, last, _ = ops.fused_flush(
+            ids, sg(msg), ts, mem_in, state["last"], w["xz"]["w"],
+            w["hz"]["w"], w["xz"]["b"], w["hz"]["b"],
             backend=cfg.kernel_backend)
     else:
-        # mean-aggregate messages per node (paper: "simply mean message")
-        zeros = jnp.zeros((n_dump + 1, cfg.msg_dim), msg.dtype)
-        sums = zeros.at[ids].add(jnp.where(live[:, None], msg, 0.0))
-        cnt = jnp.zeros((n_dump + 1,), msg.dtype).at[ids].add(
-            live.astype(msg.dtype))
-        mbar_tbl = sums / jnp.clip(cnt, 1.0)[:, None]
-
-        mbar = mbar_tbl[ids]                   # (2B, dm)
-        upd_fn = gru if cfg.updater == "gru" else rnn
-        s_new = upd_fn(params["upd"], mbar, state["mem"][ids])
-        mem = state["mem"].at[ids].set(s_new).at[n_dump].set(0.0)
+        mem = mem_in.at[ids].set(sg(rows)).at[n_dump].set(0.0)
         last = state["last"].at[ids].max(jnp.where(live, ts, 0.0))
         last = last.at[n_dump].set(0.0)
 
-    mem2 = state["mem2"]
+    fresh = {"ids": ids, "rows": rows}
+    mem2 = mem2_in
     if cfg.flavor == "tige":
-        s2_new = rnn(params["upd2"], mbar, state["mem2"][ids])
-        mem2 = state["mem2"].at[ids].set(s2_new).at[n_dump].set(0.0)
+        fresh["rows2"] = rnn(params["upd2"], mbar, mem2_in[ids])
+        mem2 = mem2_in.at[ids].set(sg(fresh["rows2"])).at[n_dump].set(0.0)
 
     b2 = ids.shape[0]
     return {
@@ -223,7 +269,7 @@ def flush_pending(params: dict, cfg: TIGConfig, state: dict) -> dict:
         "pend_ids": jnp.full((b2,), n_dump, jnp.int32),
         "pend_raw": jnp.zeros_like(raw),
         "pend_t": jnp.zeros_like(ts),
-    }
+    }, fresh
 
 
 def _stash_messages(cfg: TIGConfig, state: dict, ids_s, ids_d, t, efeat,
@@ -255,6 +301,7 @@ def embed_nodes(
     params: dict,
     cfg: TIGConfig,
     state: dict,
+    fresh: dict,             # flush_pending's fresh rows
     tables: dict,            # {"efeat": (E+1, d_e), "nfeat": (N+1, d_n)}
     ids: jnp.ndarray,        # (B,) local ids (dump row for padding)
     t: jnp.ndarray,          # (B,)
@@ -265,7 +312,7 @@ def embed_nodes(
     """The Embedding module: emb_i(t) from current memory + temporal
     neighborhood (paper Fig.6, right)."""
     n_dump = state["mem"].shape[0] - 1
-    s = _read_memory(cfg, state["mem"], state["mem2"], ids)
+    s = _read_memory(cfg, state, fresh, ids)
     nf = tables["nfeat"][ids]
     dt = t - state["last"][ids]
 
@@ -287,7 +334,7 @@ def embed_nodes(
     mask = nbr_ids >= 0
     nids = jnp.where(mask, nbr_ids, n_dump)
     eids = jnp.where(nbr_eidx >= 0, nbr_eidx, tables["efeat"].shape[0] - 1)
-    s_nbr = _read_memory(cfg, state["mem"], state["mem2"], nids)
+    s_nbr = _read_memory(cfg, state, fresh, nids)
     e_nbr = tables["efeat"][eids]
     # t is (B,): (B, 1) broadcasts against both (B, K) and (L, B, K)
     phi_nbr = time_encode(params["time"],
@@ -309,6 +356,19 @@ def embed_nodes(
 
 # -------------------------------------------------------------------- step
 
+def _fresh_read_share(cfg: TIGConfig, fresh: dict, n_dump: int, ids,
+                      nbr_ids):
+    """Share of the embedding's valid memory reads (query ids, and neighbor
+    slots where the flavor attends) that the fresh rows serve."""
+    q = [ids]
+    if cfg.uses_attention:
+        q.append(jnp.where(nbr_ids >= 0, nbr_ids, n_dump).reshape(-1))
+    q = jnp.concatenate(q)
+    hit, _ = _first_match(q, fresh["ids"], fresh["ids"] < n_dump)
+    reads = jnp.sum(q < n_dump)
+    return jnp.sum(hit) / jnp.maximum(reads, 1)
+
+
 def step_loss(
     params: dict,
     state: dict,
@@ -325,6 +385,9 @@ def step_loss(
     nbre_{r} (B,K) edge idx — or (L,B,K) each when cfg.n_layers > 1
     (roles concatenate on axis=-2 either way).  Optional: labels (B,)
     int64 (-1 unlabeled).
+
+    ``aux`` holds the logits, the src/dst embeddings, ``valid`` and
+    ``fresh_read_share`` (``_fresh_read_share``).
     """
     n_dump = state["mem"].shape[0] - 1
     valid = batch["valid"]
@@ -335,21 +398,22 @@ def step_loss(
     efeat = tables["efeat"][jnp.where(batch["eidx"] >= 0,
                                       batch["eidx"], e_dump)]
 
-    # 1) apply previous batch's messages (grads flow into MSG/UPD here)
+    # 1) apply previous batch's messages (grads flow into MSG/UPD here,
+    # through the fresh rows only)
     with jax.named_scope("tig.memory.flush"):
-        state = flush_pending(params, cfg, state)
+        state, fresh = flush_pending(params, cfg, state)
 
     # 2) embeddings at time t from the just-updated memory — the three
     # roles share one (3B,)-fused embed call (one attention launch instead
     # of three; row-wise identical math)
     b = ids_s.shape[0]
     ids_all = jnp.concatenate([ids_s, ids_d, ids_n])
+    nbr_all = jnp.concatenate([batch["nbr_src"], batch["nbr_dst"],
+                               batch["nbr_neg"]], axis=-2)
     with jax.named_scope("tig.embed"):
         emb_all = embed_nodes(
-            params, cfg, state, tables, ids_all,
-            jnp.tile(batch["t"], 3),
-            jnp.concatenate([batch["nbr_src"], batch["nbr_dst"],
-                             batch["nbr_neg"]], axis=-2),
+            params, cfg, state, fresh, tables, ids_all,
+            jnp.tile(batch["t"], 3), nbr_all,
             jnp.concatenate([batch["nbrt_src"], batch["nbrt_dst"],
                              batch["nbrt_neg"]], axis=-2),
             jnp.concatenate([batch["nbre_src"], batch["nbre_dst"],
@@ -383,5 +447,7 @@ def step_loss(
         "src_embed": embeds["src"],
         "dst_embed": embeds["dst"],
         "valid": valid,
+        "fresh_read_share": _fresh_read_share(cfg, fresh, n_dump, ids_all,
+                                              nbr_all),
     }
     return loss, (new_state, aux)

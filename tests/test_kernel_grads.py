@@ -198,12 +198,16 @@ def test_flush_pending_pallas_matches_xla_path():
             ks[1], state["pend_ids"].shape, 0, 31).astype(jnp.int32)
         state["pend_raw"] = rand(ks[2], state["pend_raw"].shape)
         state["pend_t"] = jnp.linspace(0.0, 1.0, 16)
-        out_x = flush_pending(params, cfg_x, dict(state))
-        out_p = flush_pending(params, cfg_p, dict(state))
+        out_x, fresh_x = flush_pending(params, cfg_x, dict(state))
+        out_p, fresh_p = flush_pending(params, cfg_p, dict(state))
         for key in ("mem", "mem2", "last"):
             np.testing.assert_allclose(
                 np.asarray(out_p[key]), np.asarray(out_x[key]),
                 atol=1e-6, rtol=1e-6, err_msg=f"{flavor}/{key}")
+        for key in fresh_x:
+            np.testing.assert_allclose(
+                np.asarray(fresh_p[key]), np.asarray(fresh_x[key]),
+                atol=1e-6, rtol=1e-6, err_msg=f"{flavor}/fresh {key}")
 
 
 # ------------------------------------------------------- full training step
