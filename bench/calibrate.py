@@ -1,24 +1,28 @@
-"""Readings that the limits of a cell's correctness check are set from.
+"""Readings, on a cell's own devices, that its check's limits are set from.
 
     python3 bench/calibrate.py --workload tgn-taobao.train \\
         --seeds 101 102 ... --control-seeds 201 202 203 \\
         --fault-seeds 301 302 303 --out readings
 
-For each seed, in one process and at the cell's own size, the program's
-first epoch is compared with the plain reference exactly as a run does
-(``train_epochs.compare``), and one JSON line is written per reading:
+For each seed, in one process, at the cell's own size and on the cell's
+own devices (as many chips as it asks for, found as ``bench/run.py``
+finds them), the kind of the cell's traffic (``bench/traffic/<kind>.py``)
+compares the program's first steps with the plain reference exactly as a
+run does (its ``reading``), and one JSON line is written per reading:
 
 * ``program``: the program as the configuration states it;
-* ``control``: the reference at matmul precision "high" (three bfloat16
-  passes, the step below the configurations' float32 at "highest") put in
-  the program's place;
+* ``control``: the reference at the precision below the configuration's
+  (for float32 at "highest": matmul precision "high", three bfloat16
+  passes) put in the program's place;
 * ``fault.half_batch``: the program with half of every batch left out and
   the loss taken as the mean over the rest;
 * ``fault.frozen``: the program with an optimizer step that returns the
   weights and its state unchanged.
 
-No window is measured: training's readings need none.  ``limits/`` holds
-the limits set from these readings; ``PERF.md`` gives both.
+The faults patch the step loss and the optimizer that every trainer
+shares.  No window is measured: training's readings need none.
+``limits/`` holds the limits set from these readings; ``PERF.md`` gives
+both.
 """
 
 from __future__ import annotations
@@ -68,39 +72,6 @@ def fault(name: str):
         yield
 
 
-def program_reading(conf, traffic, seed, kernel_backend="auto",
-                    fault_name=None):
-    import train_epochs as te
-
-    with fault(fault_name):
-        setup = te.Setup(conf, traffic, seed, kernel_backend)
-        with setup.prefetcher() as pf:
-            first = te.first_epoch(setup, pf)
-    k = te.check_steps(setup)
-    layout = setup.layout
-    setup.free()
-    ref = te.reference_run(conf, traffic, seed, layout, k)
-    return te.compare(first, ref), first, ref
-
-
-def control_reading(conf, traffic, seed, kernel_backend="auto"):
-    """The reference at matmul precision "high" (three bfloat16 passes) in
-    the program's place, against the reference at "highest", on the plan
-    the program would have been given."""
-    import train_epochs as te
-
-    setup = te.Setup(conf, traffic, seed, kernel_backend)
-    rows, _ = setup.plan(0)
-    k = te.check_steps(setup)
-    layout = setup.layout
-    setup.free()
-    ref = te.reference_run(conf, traffic, seed, layout, k)
-    low = te.reference_run(conf, traffic, seed, layout, k, precision="high")
-    prog = {"rows": {key: rows[key][:k] for key in ref["rows"]},
-            "losses": low["losses"], "norms": low["norms"]}
-    return te.compare(prog, ref), prog, ref
-
-
 def worst_leaves(prog, ref, n=3):
     """The leaves with the largest gaps of gradient norm and of change,
     with the number of leaves the change leaves out."""
@@ -140,8 +111,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     bench = run.spec()
     w, conf, traffic = run.cell_files(bench, args.workload)
-    run.accelerators(w["chips"])
+    devices = run.accelerators(w["chips"])
     run.compile_cache()
+    kind = run.kind_of(traffic)
     os.makedirs(args.out, exist_ok=True)
     path = Path(args.out) / f"{args.workload}.jsonl"
     jobs = [("program", s) for s in args.seeds]
@@ -151,13 +123,12 @@ def main(argv=None) -> int:
     with open(path, "a") as out:
         for what, seed in jobs:
             t0 = time.perf_counter()
-            if what == "control":
-                nums, prog, ref = control_reading(conf, traffic, seed)
-            else:
-                f = what.split(".", 1)[1] if what.startswith("fault.") \
-                    else None
-                nums, prog, ref = program_reading(conf, traffic, seed,
-                                                  fault_name=f)
+            cell = run.Cell(conf=conf, traffic=traffic, seed=seed,
+                            seconds=0.0, devices=devices)
+            f = what.split(".", 1)[1] if what.startswith("fault.") else None
+            with fault(f):
+                nums, prog, ref = kind.reading(cell,
+                                               control=what == "control")
             line = {"workload": args.workload, "reading": what,
                     "seed": seed, "numbers": nums,
                     "curve": curve(prog["losses"], ref["losses"]),

@@ -10,6 +10,28 @@ correctness check are ``bench/limits/<workload>.json``.  So a later change
 adds a cell, a mix or a metric by adding files and entries, never by
 editing one.
 
+A kind module ``bench/traffic/<kind>.py`` owns everything that is
+particular to its kind, its correctness check included, and provides:
+
+* ``run(cell) -> dict``: one run of the cell (set-up, the window, then
+  the check against the plain reference); the dict holds what the
+  metric readers read, ``numbers`` (the check's numbers by name, as
+  ``bench/limits/<workload>.json`` names them), ``steps``,
+  ``nonfinite_steps`` and ``memory_peak_bytes``;
+* ``small(conf, traffic) -> (conf, traffic)``: the cell at its own widths
+  and a size a CPU test holds;
+* ``reading(cell, control=False) -> (numbers, prog, ref)``: the check of
+  ``run`` with no window, on the program's first steps; with ``control``
+  the reference at the precision below the configuration's takes the
+  program's place.  ``prog`` and ``ref`` are ``{"rows", "losses",
+  "norms"}``: the plan rows the reference batches itself, the per-step
+  losses, and per-leaf ``{"grad", "change"}`` norms;
+* ``wrong_plan()``: a context manager that plants a plan the check must
+  refuse: its number ``plan_mismatch`` counts the entries that differ.
+
+The kind runs on ``cell.devices``: as many as the cell's ``chips``, in
+JAX's order (a one-device kind runs on the first, JAX's default).
+
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the profiler records part of the window and the result
 carries the per-layer metrics.  The last line of standard output is one
@@ -95,6 +117,11 @@ def cell_files(bench: dict, workload: str) -> tuple[dict, dict, dict]:
     return w, conf, traffic
 
 
+def kind_of(traffic: dict):
+    """The module of the traffic mix's kind, ``bench/traffic/<kind>.py``."""
+    return load_module(BENCH / "traffic" / f"{traffic['kind']}.py")
+
+
 def metrics_of(bench: dict, workload: str, trace: bool) -> list[dict]:
     group = bench["per_layer"] if trace else bench["end_to_end"]
     return [m for m in group
@@ -165,7 +192,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
     bench = bench or spec()
     _w, conf, traffic = files or cell_files(bench, workload)
     limits = load_json(BENCH / "limits" / f"{workload}.json")
-    kind = load_module(BENCH / "traffic" / f"{traffic['kind']}.py")
+    kind = kind_of(traffic)
     with tempfile.TemporaryDirectory() as tdir, \
             compile_counter() as compiles:
         cell = Cell(conf=conf, traffic=traffic, seed=seed,

@@ -17,6 +17,12 @@ follows the same steps, and the check compares the losses, the gradients
 as AdamW's second moment holds them, and the weights' change.  The window
 then runs whole epochs until ``--seconds`` have passed.
 
+The kind's check, besides ``run``: ``small`` cuts the cell to a CPU size,
+``reading`` is ``run``'s check with no window (``bench/calibrate.py``
+reads the limits' readings through it), and ``wrong_plan`` plants
+negatives that are not the seed's draw.  It runs on one device, the
+first of ``cell.devices``.
+
 Traffic file keys: ``stream_edges`` (a prefix of the configuration's
 stream, or null for all of it), ``train_frac``/``val_frac`` (the
 chronological split), ``lr``, ``max_grad_norm``, ``check_steps`` (steps of
@@ -345,6 +351,57 @@ def compare(prog: dict, ref: dict) -> dict:
                 prog["norms"]["grad"], g_ref, g_ref),
             f"change_gap.first{k}": worst_leaf_gap(
                 prog["norms"]["change"], ref["norms"]["change"], moved)}
+
+
+def small(conf: dict, traffic: dict) -> tuple[dict, dict]:
+    """The cell at its own widths, with 100 nodes, a 3,000-edge stream
+    and one traced epoch."""
+    return (dict(conf, num_users=60, num_items=40),
+            dict(traffic, stream_edges=3000, trace_epochs=1))
+
+
+def reading(cell, control=False) -> tuple[dict, dict, dict]:
+    """Epoch 0 of the program on the cell's seed, compared with the plain
+    reference as ``run`` compares them; returns (numbers, prog, ref).
+    With ``control`` the reference at matmul precision "high" (three
+    bfloat16 passes) takes the program's place, against the reference at
+    "highest", on the plan the program would have been given."""
+    setup = Setup(cell.conf, cell.traffic, cell.seed, cell.kernel_backend)
+    if control:
+        rows, _ = setup.plan(0)
+    else:
+        with setup.prefetcher() as pf:
+            prog = first_epoch(setup, pf)
+    k = check_steps(setup)
+    layout = setup.layout
+    setup.free()
+    ref = reference_run(cell.conf, cell.traffic, cell.seed, layout, k)
+    if control:
+        low = reference_run(cell.conf, cell.traffic, cell.seed, layout, k,
+                            precision="high")
+        prog = {"rows": {key: rows[key][:k] for key in ref["rows"]},
+                "losses": low["losses"], "norms": low["norms"]}
+    return compare(prog, ref), prog, ref
+
+
+@contextlib.contextmanager
+def wrong_plan():
+    """Plans whose negatives are not the seed's draw (each batch's rolled
+    by one), which the plan comparison must refuse: the reference draws
+    the epoch's negatives itself."""
+    from repro.tig import batching
+
+    orig = batching.build_batch_program
+
+    def shifted(*a, **kw):
+        batches, final = orig(*a, **kw)
+        return dict(batches, neg=np.roll(batches["neg"], 1, axis=1)), final
+
+    batching.build_batch_program = shifted
+    try:
+        yield
+    finally:
+        batching.build_batch_program = orig
 
 
 def run(cell) -> dict:
